@@ -10,7 +10,9 @@ Four small, dependency-free pieces threaded through every serving layer
                 registers into.
   tracing.py    ``Tracer`` — request spans with explicit ids on the
                 virtual microsecond clock, 1/N sampling, zero overhead
-                when disabled; JSONL + Chrome/Perfetto export.
+                when disabled; JSONL + Chrome/Perfetto export. Beside
+                it, ``stage``/``StageLog``: the served path's stages on
+                the real clock (always on), and the collector's pauses.
   jit_audit.py  ``JitAuditor`` — records every jit-variant compile
                 (cache key + wall time) and asserts the closed-variant
                 invariant online after ``freeze()``.

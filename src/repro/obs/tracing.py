@@ -38,10 +38,46 @@ Export: ``to_jsonl`` (one record per line, ``type`` = span|instant —
 what ``scripts/obs_report.py`` consumes) and ``to_chrome`` (Chrome/
 Perfetto trace-event JSON: ph="X" duration events + ph="i" instants,
 ts/dur in microseconds — load in chrome://tracing or ui.perfetto.dev).
+
+The virtual clock stays for CPU rehearsal and deterministic tests; what
+the served path spends on the real clock is recorded beside it by
+``stage`` into a ``StageLog`` (always on, ``RuntimeTelemetry.stages``),
+in ``time.perf_counter_ns`` — the clock a caller timing the runtime from
+outside reads too. Stage taxonomy:
+
+  runtime.lookup    per request, ``submit`` after the deadline sweep: the
+                    cache tiers, to the answer (hit) or the enqueue (miss).
+                    Kept as ``(idx, ns)`` in ``StageLog.lookups``.
+  queue.wait        per miss: its enqueue to the start of the dispatch
+                    that takes it, ``(idx, enqueue_ns, start_ns, id)`` in
+                    ``StageLog.waits``.
+  runtime.dispatch  per dispatch, the whole call; its children tile it:
+    runtime.batch   queue pop and input stacking,
+    frontend.route  class routing, bucket padding, the list-pad bound,
+    engine.<e>.launch  the jitted call until it returns,
+    engine.<e>.sync the blocking readback (``bool(all_done)``,
+                    ``np.asarray``),
+    runtime.scatter slicing the rows out, the caches, the answers.
+                    ``<e>`` is ``single``, ``single_full`` (the exact
+                    fallback) or ``multi``.
+
+Every stage record carries the id of the dispatch it belongs to; a miss's
+``queue.wait`` names the dispatch that served it. While a profiler session
+is active each stage (and each ``runtime.lookup``) also enters a
+``jax.profiler.TraceAnnotation`` of its name, so it lands on the trace's
+host plane on the device ops' clock; otherwise no annotation is made.
+``install_gc_clock`` adds the collector's pauses, ``(generation, t0_ns,
+t1_ns)``, process-wide (``gc_pauses()``).
 """
 from __future__ import annotations
 
+import collections
+import contextvars
+import gc
 import json
+import time
+
+from jax.profiler import TraceAnnotation
 
 
 class Tracer:
@@ -166,3 +202,124 @@ def request_trees(spans: list[dict]) -> dict:
         if root["name"] == "request" and root.get("req") is not None:
             out[root["req"]] = (root, kids.get(root["id"], []))
     return out
+
+
+# -- real-clock stages --------------------------------------------------------
+def annotation(name: str):
+    """A ``TraceAnnotation`` of ``name``, entered, while a profiler session
+    is active (the caller exits it); None otherwise, at the cost of one
+    flag read."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
+class StageLog:
+    """Real-clock stage records of one serving replica (module docstring
+    has the taxonomy). Times are ``time.perf_counter_ns``."""
+
+    def __init__(self):
+        self.spans: list[tuple] = []     # (name, t0_ns, t1_ns, dispatch, attrs)
+        self.lookups: list[tuple] = []   # (request idx, ns)
+        self.waits: list[tuple] = []     # (idx, enqueue_ns, start_ns, dispatch)
+        self.n_dispatches = 0
+
+    def dispatch(self) -> "_Dispatch":
+        """The next dispatch's ``runtime.dispatch`` stage: while it is open,
+        every ``stage`` records here under its id (``.id``)."""
+        return _Dispatch(self)
+
+    def dispatch_ns(self) -> float:
+        """Total ``runtime.dispatch`` time."""
+        return float(sum(t1 - t0 for name, t0, t1, _, _ in self.spans
+                         if name == "runtime.dispatch"))
+
+
+class stage:
+    """``with stage(name, **attrs):`` times the block on the real clock and
+    records ``(name, t0_ns, t1_ns, dispatch id, attrs)`` in the log of the
+    dispatch in progress (none outside a dispatch); annotates the profiler
+    trace while one is taken. ``t0`` (the block's entry) is readable inside
+    the block.
+
+    The record runs from where the dispatch's previous stage ended (its
+    start, for the first) to the block's exit, so a dispatch's stages tile
+    it: the few microseconds of calls and returns between two blocks count
+    in the one that follows them. The profiler annotation spans the block
+    alone."""
+
+    __slots__ = ("name", "attrs", "t0", "_ann")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs or None
+
+    def __enter__(self):
+        self._ann = annotation(self.name)
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        d = _DISPATCH.get()
+        if d is not None:
+            d.log.spans.append((self.name, d.cursor, t1, d.id, self.attrs))
+            d.cursor = t1
+        return False
+
+
+class _Dispatch(stage):
+    """``runtime.dispatch``: while open, the dispatch in progress in this
+    context, and where its last recorded stage ended (``cursor``)."""
+
+    __slots__ = ("log", "id", "cursor", "_token")
+
+    def __init__(self, log: StageLog):
+        super().__init__("runtime.dispatch")
+        self.log = log
+
+    def __enter__(self):
+        super().__enter__()
+        self.id = self.log.n_dispatches
+        self.log.n_dispatches += 1
+        self.cursor = self.t0
+        self._token = _DISPATCH.set(self)
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        _DISPATCH.reset(self._token)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.log.spans.append((self.name, self.t0, t1, self.id, None))
+        return False
+
+
+_DISPATCH: contextvars.ContextVar = contextvars.ContextVar("dispatch",
+                                                           default=None)
+_GC_PAUSES: collections.deque = collections.deque(maxlen=1 << 16)
+_gc_t0 = [0]
+
+
+def _on_gc(phase: str, info: dict):
+    if phase == "start":
+        _gc_t0[0] = time.perf_counter_ns()
+    else:
+        _GC_PAUSES.append((info["generation"], _gc_t0[0],
+                           time.perf_counter_ns()))
+
+
+def install_gc_clock():
+    """Record every collection's generation and real-clock pause from now
+    on, process-wide (the newest 65536 are kept). Idempotent."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_pauses() -> list[tuple]:
+    """The recorded collections: ``(generation, t0_ns, t1_ns)``."""
+    return list(_GC_PAUSES)

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from ..compat import default_use_kernel
 from ..core.types import INF_DOCID, MAX_TERMS
 from ..core.builder import QACIndex
+from ..obs.tracing import stage
 from .qac import serve_single_term, serve_single_term_full, serve_multi_term
 
 # VMEM ceiling for the intersect kernel's probe-list pad: beyond this the
@@ -126,11 +127,6 @@ class QACFrontend:
         # compiles) is timed + recorded, and post-freeze compiles are
         # flagged as closed-variant violations. None = unaudited.
         self.auditor = auditor
-        # per-dispatch key/route log: a tracer-enabled runtime brackets its
-        # complete() call with begin/end_dispatch_log to learn which jit
-        # variants (and therefore which kernel routes) served the batch.
-        # None = disabled — the per-_get cost is one attribute check.
-        self._dispatch_log = None
         self._route_desc: dict = {}
 
     def _multi_list_pad(self, pids, plen) -> int:
@@ -188,29 +184,19 @@ class QACFrontend:
             self._route_desc[ck] = desc
         return desc
 
-    def begin_dispatch_log(self):
-        """Start recording (jit-key, route) per ``_get`` dispatch; the
-        tracer-enabled runtime brackets each ``complete()`` call with
-        begin/end to attribute kernel routes to batch spans."""
-        self._dispatch_log = []
-
-    def end_dispatch_log(self) -> list:
-        log, self._dispatch_log = self._dispatch_log or [], None
-        return log
-
     def _get(self, engine: str, bucket: int, k: int, list_pad: int = 0):
         key = (engine, bucket, k, list_pad)
-        if self._dispatch_log is not None:
-            self._dispatch_log.append(
-                (key, self.describe_route(engine, bucket, list_pad)))
         fn = self._cache.get(key)
         if fn is None:
             # the index is an ARGUMENT of every jitted engine, never a
             # closure: a closed-over device array is baked into the
             # executable as a constant, one copy per jit variant (at the
-            # declared size, GBs of host and device memory per variant)
+            # declared size, GBs of host and device memory per variant).
+            # Each engine's function name names its XLA module
+            # (``jit_qac_single``, ``jit_qac_single_full``,
+            # ``jit_qac_multi``) in a device trace
             if engine == "single":
-                def _single(qidx, suf, slen):
+                def qac_single(qidx, suf, slen):
                     out, done = serve_single_term(
                         qidx, suf, slen, k=k, trips=self.trips,
                         use_kernel=self.use_kernel, interpret=self.interpret,
@@ -219,13 +205,17 @@ class QACFrontend:
                         heap_kernel_max_bytes=self.heap_kernel_max_bytes)
                     return out, jnp.all(done)   # scalar: one tiny host sync
 
-                jitted = jax.jit(_single)
+                jitted = jax.jit(qac_single)
             elif engine == "single_full":
-                jitted = jax.jit(lambda qidx, suf, slen: serve_single_term_full(
-                    qidx, suf, slen, k=k, use_kernel=self.use_kernel,
-                    interpret=self.interpret, heap_kernel=self.heap_kernel,
-                    postings_codec=self.postings_codec,
-                    heap_kernel_max_bytes=self.heap_kernel_max_bytes))
+                def qac_single_full(qidx, suf, slen):
+                    return serve_single_term_full(
+                        qidx, suf, slen, k=k, use_kernel=self.use_kernel,
+                        interpret=self.interpret,
+                        heap_kernel=self.heap_kernel,
+                        postings_codec=self.postings_codec,
+                        heap_kernel_max_bytes=self.heap_kernel_max_bytes)
+
+                jitted = jax.jit(qac_single_full)
             elif engine == "multi":
                 # the compressed probe route replaces the [B, P, L] gather
                 # with the whole packed index, so the list_pad/HBM gates
@@ -235,13 +225,16 @@ class QACFrontend:
                     or (list_pad <= MAX_LIST_PAD
                         and bucket * MAX_TERMS * list_pad * 4
                         <= MAX_MULTI_KERNEL_BYTES))
-                jitted = jax.jit(
-                    lambda qidx, pids, plen, suf, slen: serve_multi_term(
+
+                def qac_multi(qidx, pids, plen, suf, slen):
+                    return serve_multi_term(
                         qidx, pids, plen, suf, slen, k=k, tile=self.tile,
                         max_tiles=self.max_tiles, use_kernel=use_k,
                         interpret=self.interpret, list_pad=list_pad,
                         probe_iters=list_pad.bit_length(),
-                        postings_codec=self.postings_codec))
+                        postings_codec=self.postings_codec)
+
+                jitted = jax.jit(qac_multi)
             else:
                 raise ValueError(engine)
             fn = functools.partial(jitted, self.qidx)
@@ -266,14 +259,25 @@ class QACFrontend:
         return 1 << max(0, (ki - 1).bit_length())
 
     # -- serving --------------------------------------------------------------
-    def _run_single(self, bucket: int, k: int, suf, slen):
-        res, all_done = self._get("single", bucket, k)(suf, slen)
-        if not bool(all_done):
-            # a lane needed more than `trips` pops (duplicate-docid run):
-            # recompute the sub-batch with the exact full-budget engine
-            self.stats["single_fallbacks"] += 1
-            res = self._get("single_full", bucket, k)(suf, slen)
-        return np.asarray(res)
+    # A call is timed in stages (obs/tracing.py): ``frontend.route`` (class
+    # partition, bucket padding, list-pad bound, and the rows' return to
+    # request order), then per class the jitted call until it returns
+    # (``engine.<e>.launch``, attrs = its jit key) and the blocking readback
+    # (``engine.<e>.sync``)
+    def _run(self, engine: str, bucket: int, k: int, list_pad: int, args):
+        with stage(f"engine.{engine}.launch", bucket=bucket, k=k,
+                   list_pad=list_pad):
+            res = self._get(engine, bucket, k, list_pad)(*args)
+        with stage(f"engine.{engine}.sync"):
+            if engine != "single":
+                return np.asarray(res)
+            res, all_done = res
+            if bool(all_done):
+                return np.asarray(res)
+        # a lane needed more than `trips` pops (duplicate-docid run):
+        # recompute the sub-batch with the exact full-budget engine
+        self.stats["single_fallbacks"] += 1
+        return self._run("single_full", bucket, k, 0, args)
 
     def complete(self, prefix_ids, prefix_len, suffix_chars, suffix_len, *,
                  k: int | np.ndarray | None = None):
@@ -334,6 +338,16 @@ class QACFrontend:
 
     def _complete_scalar(self, prefix_ids, prefix_len, suffix_chars,
                          suffix_len, k: int):
+        with stage("frontend.route"):
+            plan = self._route(prefix_ids, prefix_len, suffix_chars,
+                               suffix_len, k)
+        return self._serve(*plan)
+
+    def _route(self, prefix_ids, prefix_len, suffix_chars, suffix_len,
+               k: int):
+        """Host-side class routing -> (B, k, calls): per class present, one
+        (engine, bucket, list_pad, inputs, rows) call; ``rows`` is None when
+        the batch goes to its engine as it is."""
         plen = np.asarray(prefix_len)
         B = plen.shape[0]
         single_rows, multi_rows = route_classes(plen)
@@ -347,27 +361,36 @@ class QACFrontend:
         # for the per-bucket list_pad — free when the caller passes
         # parse_queries' numpy output, a device sync otherwise
         if single_rows.size == B and self._bucket(B) == B:
-            return self._run_single(B, k, suffix_chars, suffix_len)
+            return B, k, [("single", B, 0, (suffix_chars, suffix_len), None)]
         if multi_rows.size == B and self._bucket(B) == B:
             lp = self._multi_list_pad(np.asarray(prefix_ids), plen)
-            return np.asarray(self._get("multi", B, k, lp)(
-                prefix_ids, plen, suffix_chars, suffix_len))
+            return B, k, [("multi", B, lp, (prefix_ids, plen, suffix_chars,
+                                            suffix_len), None)]
 
         pids = np.asarray(prefix_ids)
         suf = np.asarray(suffix_chars)
         slen = np.asarray(suffix_len)
-        out = np.full((B, k), INF_DOCID, np.int32)
-
+        calls = []
         if single_rows.size:
             pad = np.resize(single_rows, self._bucket(single_rows.size))
-            res = self._run_single(len(pad), k, suf[pad], slen[pad])
-            out[single_rows] = res[: single_rows.size]
-
+            calls.append(("single", len(pad), 0, (suf[pad], slen[pad]),
+                          single_rows))
         if multi_rows.size:
             pad = np.resize(multi_rows, self._bucket(multi_rows.size))
             lp = self._multi_list_pad(pids[pad], plen[pad])
-            res = self._get("multi", len(pad), k, lp)(
-                pids[pad], plen[pad], suf[pad], slen[pad])
-            out[multi_rows] = np.asarray(res)[: multi_rows.size]
+            calls.append(("multi", len(pad), lp,
+                          (pids[pad], plen[pad], suf[pad], slen[pad]),
+                          multi_rows))
+        return B, k, calls
 
+    def _serve(self, B: int, k: int, calls):
+        """Each class's engine call, then its rows back into request order."""
+        res = [self._run(engine, bucket, k, lp, args)
+               for engine, bucket, lp, args, _ in calls]
+        if len(calls) == 1 and calls[0][4] is None:
+            return res[0]
+        with stage("frontend.route"):
+            out = np.full((B, k), INF_DOCID, np.int32)
+            for (_, _, _, _, rows), r in zip(calls, res):
+                out[rows] = r[: rows.size]
         return out

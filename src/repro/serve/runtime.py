@@ -24,7 +24,8 @@ missed under that load. This module is the layer in between:
     checks every interleaving against direct per-request calls).
   * **telemetry** — per-request latency percentiles (p50/p95/p99), queue
     depth (max-depth gauge), deadline-violation counter, batch-size
-    histogram, dispatch triggers, cache hit rate.
+    histogram, dispatch triggers, cache hit rate, and the real-clock stage
+    records (``RuntimeTelemetry.stages``; taxonomy in ``obs/tracing.py``).
 
 One instance of this class is ONE serving replica, and on its own it never
 sheds load: the queue is unbounded and every admitted request is served no
@@ -76,6 +77,7 @@ import numpy as np
 from ..core.builder import QACIndex, parse_queries
 from ..core.types import INF_DOCID
 from ..obs.metrics import percentiles
+from ..obs.tracing import StageLog, annotation, install_gc_clock, stage
 from .frontend import QACFrontend
 
 
@@ -133,6 +135,7 @@ class QACRequest:
     hi: int
     key: tuple
     deadline: float = 0.0
+    enq_ns: int = 0       # real clock (perf_counter_ns) of its enqueue
 
 
 def prepare_requests(qidx: QACIndex, trace, *, k: int | np.ndarray = 10):
@@ -185,7 +188,9 @@ class RuntimeTelemetry:
         self.batch_sizes: list[int] = []
         self.triggers: Counter = Counter()
         self.queue_peak = 0
-        self.engine_wall_us = 0.0
+        # real-clock stages: lookups, queue waits, dispatches and their
+        # parts (obs/tracing.py has the taxonomy)
+        self.stages = StageLog()
         # a deadline violation = a dispatch that STARTED after the oldest
         # batched request's (arrival + slack) deadline — the server was so
         # backed up the batching budget was already blown before service
@@ -199,6 +204,11 @@ class RuntimeTelemetry:
         # per tier) — tests assert count == 1 per transition.
         self.paths_by_gen: dict[int, Counter] = {}
         self.invalidations: dict[tuple[int, int], dict] = {}
+
+    @property
+    def engine_wall_us(self) -> float:
+        """Real-clock time spent in dispatches (``runtime.dispatch``)."""
+        return self.stages.dispatch_ns() / 1e3
 
     def record(self, path: str, lat_us: float, gen: int | None = None):
         self.paths[path] += 1
@@ -238,7 +248,7 @@ class RuntimeTelemetry:
             "queue_peak": self.queue_peak,
             "max_queue_depth": self.queue_peak,
             "deadline_violations": self.deadline_violations,
-            "engine_wall_us": float(self.engine_wall_us),
+            "engine_wall_us": self.engine_wall_us,
             "per_generation": {g: dict(c)
                                for g, c in sorted(self.paths_by_gen.items())},
             "invalidations": {f"{o}->{n}": dict(v) for (o, n), v in
@@ -279,6 +289,7 @@ class QACOnlineRuntime:
         # is the ONLY way to advance it — reset() deliberately leaves it
         # alone (it is index identity, not cache state).
         self.generation = 0
+        install_gc_clock()
         self.reset()
 
     def reset(self):
@@ -457,24 +468,43 @@ class QACOnlineRuntime:
         queue it for the next micro-batch. Call in arrival-time order."""
         now = r.t_us
         self._advance(now)
-        t0 = time.perf_counter()
+        ann = annotation("runtime.lookup")
+        t0 = time.perf_counter_ns()
+        path, row, attrs = self._lookup(r)
+        t1 = time.perf_counter_ns()
+        self.telemetry.stages.lookups.append((r.idx, t1 - t0))
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if path is not None:
+            lat = (t1 - t0) / 1e3
+            self._finish(r, row, path, lat)
+            self._trace_hit(r, path, lat, **attrs)
+            return
+        if self.tracer is not None and self.tracer.want(r.idx):
+            self.tracer.instant("cache.miss", now, cat="cache", req=r.idx,
+                                reason=self._miss_reason(r, attrs["sess"]))
+        r.deadline = now + self.cfg.slack_us
+        r.enq_ns = t1
+        self.queue.append(r)
+        self.telemetry.queue_peak = max(self.telemetry.queue_peak,
+                                        len(self.queue))
+        while len(self.queue) >= self.cfg.max_batch:
+            self._dispatch(max(now, self._server_free), "full")
+
+    def _lookup(self, r: QACRequest):
+        """The cache tiers for one request -> (path, row, trace attrs) when
+        they answer it, else (None, None, {"sess": its session entry})."""
         if self._is_bad(r):
             row = np.full(r.k, INF_DOCID, np.int32)
             self._remember(r, row, row[:0])
-            lat = (time.perf_counter() - t0) * 1e6
-            self._finish(r, row, "trivial", lat)
-            self._trace_hit(r, "trivial", lat, reason="engine_reject")
-            return
+            return "trivial", row, {"reason": "engine_reject"}
         if self.cfg.cache_entries > 0:
             ck = (self.generation, r.key, r.k)
             hit = self.cache.get(ck)
             if hit is not None:
                 self.cache.move_to_end(ck)
                 self._remember(r, hit, None)
-                lat = (time.perf_counter() - t0) * 1e6
-                self._finish(r, hit.copy(), "hit_exact", lat)
-                self._trace_hit(r, "hit_exact", lat, reason="lru_exact")
-                return
+                return "hit_exact", hit.copy(), {"reason": "lru_exact"}
         sess = (self.sessions.get(r.session)
                 if self.cfg.session_entries > 0 else None)
         if self._reusable(sess, r):
@@ -483,20 +513,9 @@ class QACOnlineRuntime:
             row = np.full(r.k, INF_DOCID, np.int32)
             row[: min(r.k, keep.size)] = keep[: r.k]
             self._remember(r, row, keep)
-            lat = (time.perf_counter() - t0) * 1e6
-            self._finish(r, row, "hit_session", lat)
-            self._trace_hit(r, "hit_session", lat, reason="subset_filter",
-                            n_candidates=int(cand.size))
-            return
-        if self.tracer is not None and self.tracer.want(r.idx):
-            self.tracer.instant("cache.miss", now, cat="cache", req=r.idx,
-                                reason=self._miss_reason(r, sess))
-        r.deadline = now + self.cfg.slack_us
-        self.queue.append(r)
-        self.telemetry.queue_peak = max(self.telemetry.queue_peak,
-                                        len(self.queue))
-        while len(self.queue) >= self.cfg.max_batch:
-            self._dispatch(max(now, self._server_free), "full")
+            return "hit_session", row, {"reason": "subset_filter",
+                                        "n_candidates": int(cand.size)}
+        return None, None, {"sess": sess}
 
     def _advance(self, now: float):
         """Fire every deadline-triggered dispatch that happens before
@@ -510,40 +529,55 @@ class QACOnlineRuntime:
     def _dispatch(self, t_start: float, trigger: str):
         """Form one micro-batch (oldest-first, only requests that have
         arrived by t_start) and run it through the frontend; the measured
-        wall time advances the virtual server clock."""
-        batch = []
-        while (self.queue and len(batch) < self.cfg.max_batch
-               and self.queue[0].t_us <= t_start):
-            batch.append(self.queue.popleft())
-        # every call site guarantees t_start >= the head's arrival time
-        # (deadline = arrival + slack, full-trigger uses now) — a violation
-        # would mean serving a request before it arrived
-        assert batch, "dispatch scheduled before the queue head's arrival"
+        wall time (pop to answers) advances the virtual server clock."""
+        tel = self.telemetry
+        log = tel.stages
+        with log.dispatch() as d:
+            with stage("runtime.batch"):
+                batch = []
+                while (self.queue and len(batch) < self.cfg.max_batch
+                       and self.queue[0].t_us <= t_start):
+                    r = self.queue.popleft()
+                    log.waits.append((r.idx, r.enq_ns, d.t0, d.id))
+                    batch.append(r)
+                # every call site guarantees t_start >= the head's arrival
+                # (deadline = arrival + slack, full-trigger uses now) — a
+                # violation would mean serving a request before it arrived
+                assert batch, "dispatch scheduled before the queue head's arrival"
+                pids = np.stack([r.pids for r in batch])
+                plen = np.asarray([r.plen for r in batch], np.int32)
+                suf = np.stack([r.suf for r in batch])
+                slen = np.asarray([r.slen for r in batch], np.int32)
+                # the frontend's array-k path owns the scalar-vs-bucketed
+                # routing (only the default k collapses to a raw scalar
+                # dispatch)
+                ks = np.asarray([r.k for r in batch], np.int32)
+            n_spans = len(log.spans)
+            out = self.fe.complete(pids, plen, suf, slen, k=ks)
+            with stage("runtime.scatter") as sc:
+                dt_us = (sc.t0 - d.t0) / 1e3
+                self._server_free = t_start + dt_us
+                self._scatter(batch, np.asarray(out), t_start, dt_us,
+                              trigger, log.spans[n_spans:])
+
+    def _scatter(self, batch, out, t_start, dt_us, trigger, engine_spans):
+        """A dispatched batch's rows -> caches, answers and telemetry."""
         tr = self.tracer
         traced = tr is not None and any(tr.want(r.idx) for r in batch)
         if traced:
-            self.fe.begin_dispatch_log()
-        t0 = time.perf_counter()
-        pids = np.stack([r.pids for r in batch])
-        plen = np.asarray([r.plen for r in batch], np.int32)
-        suf = np.stack([r.suf for r in batch])
-        slen = np.asarray([r.slen for r in batch], np.int32)
-        # the frontend's array-k path owns the scalar-vs-bucketed routing
-        # (only the default k collapses to a raw scalar dispatch)
-        ks = np.asarray([r.k for r in batch], np.int32)
-        out = np.asarray(self.fe.complete(pids, plen, suf, slen, k=ks))
-        dt_us = (time.perf_counter() - t0) * 1e6
-        self._server_free = t_start + dt_us
-        if traced:
-            dlog = self.fe.end_dispatch_log()
+            # the jit variants (and so the kernel routes) that served the
+            # batch, from the frontend's engine launch stages
+            keys = [(name.split(".")[1], a["bucket"], a["k"], a["list_pad"])
+                    for name, _, _, _, a in engine_spans
+                    if name.endswith(".launch")]
             tr.span("batch.dispatch", t_start, dt_us, cat="batch",
                     size=len(batch), trigger=trigger,
-                    jit_keys=[list(key) for key, _ in dlog],
-                    routes=sorted({route for _, route in dlog}))
+                    jit_keys=[list(key) for key in keys],
+                    routes=sorted({self.fe.describe_route(e, b, lp)
+                                   for e, b, _, lp in keys}))
         tel = self.telemetry
         tel.batch_sizes.append(len(batch))
         tel.triggers[trigger] += 1
-        tel.engine_wall_us += dt_us
         tel.deadline_violations += sum(t_start > r.deadline for r in batch)
         if self.on_dispatch is not None:
             self.on_dispatch(len(batch), dt_us, t_start)

@@ -346,3 +346,98 @@ def test_obs_config_factories():
     assert aud.tracer is tr
     assert cfg.slo_monitor().target_us == 10_000.0
     assert isinstance(cfg.registry(), MetricsRegistry)
+
+
+# ------------------------------------------------------ real-clock stages
+def _served(built, **cfg):
+    qidx, kept, fe = built
+    trace = generate_keystroke_trace(kept, KeystrokeTraceConfig(
+        n_sessions=8, mean_keystroke_ms=5.0, session_spread_ms=20.0,
+        seed=4))
+    reqs = prepare_requests(qidx, trace, k=10)
+    rt = QACOnlineRuntime(fe, RuntimeConfig(max_batch=8, slack_us=1_000.0),
+                          **cfg)
+    rt.run_trace(reqs)
+    return reqs, rt
+
+
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts entries."""
+
+    enabled = False
+    entered: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.enabled
+
+    def __enter__(self):
+        self.entered.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.mark.parametrize("active", [False, True],
+                         ids=["profiler_inactive", "profiler_active"])
+def test_stage_annotations_only_while_a_profiler_runs(built, monkeypatch,
+                                                      active):
+    from repro.obs import tracing
+    monkeypatch.setattr(_CountingAnnotation, "enabled", active)
+    monkeypatch.setattr(_CountingAnnotation, "entered", [])
+    monkeypatch.setattr(tracing, "TraceAnnotation", _CountingAnnotation)
+    reqs, rt = _served(built)
+    entered = _CountingAnnotation.entered
+    if not active:
+        assert entered == []
+    else:
+        # one per request's lookup, one per recorded stage
+        assert entered.count("runtime.lookup") == len(reqs)
+        assert len(entered) == len(reqs) + len(rt.telemetry.stages.spans)
+
+
+def test_stage_names_stay_clear_of_the_benchmark_spans(built):
+    """The benchmark collects its own host spans by name; a program stage
+    of the same name would change what those spans measure."""
+    from bench import stages, traces
+    _, rt = _served(built)
+    names = {n for n, *_ in rt.telemetry.stages.spans} | {"runtime.lookup"}
+    assert {"runtime.dispatch", "runtime.batch", "frontend.route",
+            "runtime.scatter"} <= names
+    for n in names:
+        assert n not in traces.HOST_SPANS and n != "window"
+        assert n.startswith(stages.PROGRAM)
+
+
+def test_batch_dispatch_span_takes_keys_and_routes_from_the_stages(built):
+    tr = Tracer(sample_every=1)
+    _, rt = _served(built, tracer=tr)
+    batches = [s for s in tr.spans if s["name"] == "batch.dispatch"]
+    launches = [(n, a) for n, _, _, _, a in rt.telemetry.stages.spans
+                if n.endswith(".launch")]
+    assert len(batches) == len(rt.telemetry.batch_sizes)
+    keys = [k for b in batches for k in b["attrs"]["jit_keys"]]
+    assert keys == [[n.split(".")[1], a["bucket"], a["k"], a["list_pad"]]
+                    for n, a in launches]
+    for b in batches:
+        assert b["attrs"]["routes"]
+        assert {k[0] for k in b["attrs"]["jit_keys"]} <= {
+            "single", "single_full", "multi"}
+
+
+def test_gc_clock_records_each_collection_once():
+    import gc
+    import time
+    from repro.obs import tracing
+    tracing.install_gc_clock()
+    tracing.install_gc_clock()
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    t = time.perf_counter_ns()
+    gc.collect()
+    new = [p for p in tracing.gc_pauses() if p[1] >= t]
+    assert [g for g, _, _ in new].count(2) == 1
+    assert all(t1 >= t0 for _, t0, t1 in new)

@@ -373,3 +373,112 @@ def test_trace_target_qps_rescales_and_is_deterministic(built):
     with pytest.raises(ValueError):
         generate_keystroke_trace(kept, KeystrokeTraceConfig(
             n_sessions=2, seed=13, target_qps=-1.0))
+
+
+# ------------------------------------------- real-clock stages (obs/tracing)
+@pytest.fixture(scope="module")
+def staged(built):
+    """One trace served with the stage records on (they always are)."""
+    qidx, kept, fe = built
+    trace = generate_keystroke_trace(kept, KeystrokeTraceConfig(
+        n_sessions=24, mean_keystroke_ms=5.0, session_spread_ms=20.0,
+        seed=21))
+    reqs = prepare_requests(qidx, trace, k=10)
+    rt = QACOnlineRuntime(fe, RuntimeConfig(max_batch=16, slack_us=2_000.0))
+    got = rt.run_trace(reqs)
+    return reqs, rt, got
+
+
+def _by_dispatch(log):
+    out = {}
+    for name, t0, t1, d, attrs in log.spans:
+        out.setdefault(d, []).append((name, t0, t1, attrs))
+    return out
+
+
+def test_dispatch_stages_tile_each_dispatch(staged):
+    _, rt, _ = staged
+    disp = _by_dispatch(rt.telemetry.stages)
+    assert len(disp) == len(rt.telemetry.batch_sizes) > 0
+    for d, spans in disp.items():
+        (p0, p1), = [(t0, t1) for n, t0, t1, _ in spans
+                     if n == "runtime.dispatch"]
+        kids = [(n, t0, t1) for n, t0, t1, _ in spans
+                if n != "runtime.dispatch"]
+        names = [n for n, _, _ in kids]
+        assert names[0] == "runtime.batch" and names[-1] == "runtime.scatter"
+        assert "frontend.route" in names
+        assert any(n.endswith(".launch") for n in names)
+        assert any(n.endswith(".sync") for n in names)
+        assert all(p0 <= t0 <= t1 <= p1 for _, t0, t1 in kids)
+        covered = sum(t1 - t0 for _, t0, t1 in kids)
+        assert abs((p1 - p0) - covered) <= max(0.02 * (p1 - p0), 20_000), d
+
+
+def test_queue_wait_once_per_miss_naming_its_dispatch(staged):
+    reqs, rt, _ = staged
+    log = rt.telemetry.stages
+    starts = {d: t0 for n, t0, _, d, _ in log.spans
+              if n == "runtime.dispatch"}
+    misses = sorted(r.idx for r in reqs if rt.done_path[r.idx] == "miss")
+    assert misses and sorted(w[0] for w in log.waits) == misses
+    for idx, t_enq, t_start, d in log.waits:
+        assert t_start - t_enq >= 0
+        assert starts[d] == t_start
+    assert {w[3] for w in log.waits} == set(starts)
+
+
+def test_lookup_recorded_once_per_request(staged):
+    reqs, rt, _ = staged
+    lookups = rt.telemetry.stages.lookups
+    assert sorted(i for i, _ in lookups) == [r.idx for r in reqs]
+    assert all(ns >= 0 for _, ns in lookups)
+
+
+def test_stage_records_leave_answers_bit_identical(built, staged):
+    """The stage-free path is a frontend call outside any dispatch: it
+    records nothing, and every served answer equals it."""
+    _, _, fe = built
+    reqs, rt, got = staged
+    n = len(rt.telemetry.stages.spans)
+    _assert_parity(fe, reqs, got)
+    assert len(rt.telemetry.stages.spans) == n
+
+
+def test_engine_wall_us_sums_the_dispatch_records(staged):
+    _, rt, _ = staged
+    total = sum(t1 - t0 for n, t0, t1, _, _ in rt.telemetry.stages.spans
+                if n == "runtime.dispatch")
+    snap = rt.telemetry.snapshot()
+    assert snap["engine_wall_us"] == pytest.approx(total / 1e3) and total > 0
+
+
+def test_forced_fallback_shows_a_single_full_stage(built):
+    """trips=1 leaves lanes unfinished: the exact fallback runs, and its
+    launch record is the fallback count."""
+    qidx, kept, fe = built
+    fe1 = QACFrontend(qidx, k=10, trips=1, specialize_list_pad=False)
+    words = sorted({q.split()[0] for q in kept})[:12]
+    reqs = prepare_requests(qidx, [(float(i), i, w[:2])
+                                   for i, w in enumerate(words)], k=10)
+    rt = QACOnlineRuntime(fe1, RuntimeConfig(
+        max_batch=8, slack_us=1e9, cache_entries=0, session_entries=0))
+    got = rt.run_trace(reqs)
+    _assert_parity(fe, reqs, got)
+    names = [n for n, *_ in rt.telemetry.stages.spans]
+    n_full = names.count("engine.single_full.launch")
+    assert n_full >= 1 and n_full == fe1.stats["single_fallbacks"]
+    assert names.count("engine.single_full.sync") == n_full
+
+
+def test_reset_clears_the_stage_records(built):
+    qidx, kept, fe = built
+    reqs = prepare_requests(qidx, [(0.0, 0, kept[7])], k=10)
+    rt = QACOnlineRuntime(fe, RuntimeConfig(max_batch=1, slack_us=0.0))
+    rt.run_trace(reqs)
+    log = rt.telemetry.stages
+    assert log.spans and log.lookups and log.waits
+    rt.reset()
+    log = rt.telemetry.stages
+    assert not (log.spans or log.lookups or log.waits)
+    assert rt.telemetry.snapshot()["engine_wall_us"] == 0.0
