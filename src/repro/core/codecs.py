@@ -389,24 +389,20 @@ def pack_postings(postings: np.ndarray, codec: str = "ef") -> PackedPostings:
     wordoff = np.concatenate([[0], np.cumsum(nwords)[:-1]])
     total = int(nwords.sum())
 
-    # blocks are uint64-aligned (every payload is an even word count), so
-    # one sequential BitWriter produces the whole stream
-    bw = BitWriter()
-    for b in range(nb):
-        if use_ef[b]:
-            start = bw.n_bits()
-            bw.unary_many(np.diff(d[b] >> int(l[b]), prepend=np.int64(0)))
-            bw.pad_to(start + EF_BITMAP_WORDS * 32)
-            bw.write_many(d[b] & ((1 << int(l[b])) - 1), int(l[b]))
-        elif width[b] > 0:
-            bw.write_many(d[b], int(width[b]))
-    assert bw.n_bits() == total * 32
-    w64 = np.zeros(max(total + 1, 2) // 2, dtype=_U64)
-    got = bw.array()[: len(w64)]
-    w64[: len(got)] = got
-    words32 = np.empty(max(total, 1), dtype=np.uint32)
-    words32[0::2] = (w64 & _U64(0xFFFFFFFF)).astype(np.uint32)[: len(words32[0::2])]
-    words32[1::2] = (w64 >> _U64(32)).astype(np.uint32)[: len(words32[1::2])]
+    words32 = np.zeros(max(total, 1), dtype=np.uint32)
+    at = wordoff + np.where(use_ef, EF_BITMAP_WORDS, 0)   # fixed-width fields
+    for w in np.unique(wfield[wfield > 0]).tolist():
+        sel = np.flatnonzero(wfield == w)
+        words32[at[sel, None] + np.arange(4 * w)] = _pack_fields(
+            d[sel] & ((1 << w) - 1), w)
+    ef = np.flatnonzero(use_ef)
+    if ef.size:
+        # EF upper bits: bitmap bit j + high_j set, high_j = delta_j >> l
+        bits = np.zeros((ef.size, 32 * EF_BITMAP_WORDS), np.uint8)
+        bits[np.arange(ef.size)[:, None],
+             np.arange(PACK_BLOCK) + (d[ef] >> wfield[ef, None])] = 1
+        words32[wordoff[ef, None] + np.arange(EF_BITMAP_WORDS)] = np.packbits(
+            bits, axis=1, bitorder="little").view("<u4")
 
     meta = wfield | (use_ef.astype(np.int64) << _META_EF_BIT)
     return PackedPostings(
@@ -417,31 +413,63 @@ def pack_postings(postings: np.ndarray, codec: str = "ef") -> PackedPostings:
         n_post=n, codec=codec)
 
 
+def _field_layout(w: int):
+    """Where each of 32 ``w``-bit fields lies in the ``w`` words they
+    fill: (word, bit shift)."""
+    bit = np.arange(32) * w
+    return bit >> 5, (bit & 31).astype(_U64)
+
+
+def _pack_fields(v: np.ndarray, w: int) -> np.ndarray:
+    """uint64[m, 128] values under 2**w -> their little-endian bit stream,
+    uint32[m, 4w]."""
+    v = v.astype(_U64).reshape(-1, 32)
+    word, sh = _field_layout(w)
+    # every word holds the start of a field (w <= 32), and fields are
+    # disjoint: a word is the sum of the low parts that start in it plus
+    # the spill of the one before
+    out = np.add.reduceat((v << sh) & _U64(0xFFFFFFFF),
+                          np.flatnonzero(np.diff(word, prepend=-1)), axis=1)
+    spill = np.flatnonzero(sh + _U64(w) > _U64(32))
+    out[:, word[spill] + 1] += v[:, spill] >> (_U64(32) - sh[spill])
+    return out.reshape(-1, 4 * w).astype(np.uint32)
+
+
+def _unpack_fields(words: np.ndarray, w: int) -> np.ndarray:
+    """uint64[m, 4w] little-endian bit streams -> their 128 ``w``-bit
+    values each, int64[m, 128]."""
+    words = words.reshape(-1, w)
+    word, sh = _field_layout(w)
+    v = ((words[:, word] >> sh)
+         | (words[:, np.minimum(word + 1, w - 1)] << (_U64(32) - sh)))
+    return (v & _U64((1 << w) - 1)).astype(np.int64).reshape(-1, PACK_BLOCK)
+
+
 def unpack_postings(pk: PackedPostings) -> np.ndarray:
     """Host reference decode of the full stream -> int32[n_post]."""
     words = np.asarray(pk.words).view(np.uint32)
     base = np.asarray(pk.base, dtype=np.int64)
-    meta = np.asarray(pk.meta)
+    meta = np.asarray(pk.meta, dtype=np.int64)
     wordoff = np.asarray(pk.wordoff, dtype=np.int64)
-    nb = len(base)
-    out = np.empty(nb * PACK_BLOCK, dtype=np.int64)
-    for b in range(nb):
-        w = int(meta[b]) & ((1 << _META_EF_BIT) - 1)
-        is_ef = (int(meta[b]) >> _META_EF_BIT) & 1
-        nw = (EF_BITMAP_WORDS + 4 * w) if is_ef else 4 * w
-        seg = words[wordoff[b] : wordoff[b] + nw].astype(_U64)
-        w64 = seg[0::2] | (seg[1::2] << _U64(32))
-        if nw == 0:
-            d = np.zeros(PACK_BLOCK, dtype=np.int64)
-        elif is_ef:
-            r = BitReader(w64)
-            high = np.cumsum(r.unary_many(PACK_BLOCK))
-            r.pos = EF_BITMAP_WORDS * 32
-            d = (high << w) | r.read_many(PACK_BLOCK, w)
-        else:
-            d = BitReader(w64).read_many(PACK_BLOCK, w)
-        out[b * PACK_BLOCK : (b + 1) * PACK_BLOCK] = base[b] + d
-    return out[: pk.n_post].astype(np.int32)
+    wf = meta & ((1 << _META_EF_BIT) - 1)
+    is_ef = ((meta >> _META_EF_BIT) & 1).astype(bool)
+    d = np.zeros((len(base), PACK_BLOCK), dtype=np.int64)
+    at = wordoff + np.where(is_ef, EF_BITMAP_WORDS, 0)
+    for w in np.unique(wf[wf > 0]).tolist():
+        sel = np.flatnonzero(wf == w)
+        d[sel] = _unpack_fields(
+            words[at[sel, None] + np.arange(4 * w)].astype(_U64), w)
+    ef = np.flatnonzero(is_ef)
+    if ef.size:
+        # an EF block's j-th set bitmap bit lies at j + high_j
+        bm = words[wordoff[ef, None] + np.arange(EF_BITMAP_WORDS)]
+        bits = np.unpackbits(bm.astype("<u4").view(np.uint8), axis=1,
+                             bitorder="little")
+        _, pos = np.nonzero(bits)
+        assert pos.size == ef.size * PACK_BLOCK, "EF bitmap broken"
+        d[ef] |= (pos.reshape(-1, PACK_BLOCK)
+                  - np.arange(PACK_BLOCK)) << wf[ef, None]
+    return (base[:, None] + d).ravel()[: pk.n_post].astype(np.int32)
 
 
 def _popcount32(x):

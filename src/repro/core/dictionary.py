@@ -30,13 +30,17 @@ class TermDictionary:
     @staticmethod
     def build(terms, max_chars: int = MAX_TERM_CHARS) -> "TermDictionary":
         """terms: iterable of unique strings (host side)."""
-        terms = sorted(set(terms))
-        chars = encode_strings(terms, max_chars)
-        keys = pack_chars(chars)
+        return TermDictionary.from_chars(
+            encode_strings(sorted(set(terms)), max_chars), max_chars)
+
+    @staticmethod
+    def from_chars(chars: np.ndarray,
+                   max_chars: int = MAX_TERM_CHARS) -> "TermDictionary":
+        """chars: uint8[V, max_chars], the sorted terms' bytes, 0-padded."""
         return TermDictionary(
             chars=jnp.asarray(chars),
-            keys=jnp.asarray(keys),
-            n_terms=len(terms),
+            keys=jnp.asarray(pack_chars(chars)),
+            n_terms=len(chars),
             max_chars=max_chars,
         )
 

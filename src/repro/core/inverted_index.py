@@ -19,6 +19,7 @@ from .types import INF_DOCID, pytree_dataclass
 from .searching import ranged_searchsorted, next_geq
 from .codecs import PackedPostings, pack_postings, unpack_postings
 from .rmq import RangeMin
+from ..obs.tracing import build_stage
 
 
 @pytree_dataclass(meta_fields=("n_terms", "n_postings"))
@@ -38,35 +39,17 @@ class InvertedIndex:
         ``postings_codec``: "ef" (default) or "bitpack" additionally emits
         the compressed device layout into ``.packed``; None skips it.
         """
-        term_rows = np.asarray(term_rows, dtype=np.int64)
-        n, m = term_rows.shape
-        docs = np.broadcast_to(np.asarray(docid_of_row, dtype=np.int64)[:, None], (n, m))
-        mask = term_rows != 0
-        t = term_rows[mask]
-        d = docs[mask]
-        # dedup (term, doc) pairs — a term may repeat inside one completion
-        key = t * (np.int64(docid_of_row.max()) + 1) + d
-        uniq = np.unique(key)
-        t = (uniq // (np.int64(docid_of_row.max()) + 1)).astype(np.int64)
-        d = (uniq % (np.int64(docid_of_row.max()) + 1)).astype(np.int64)
-        order = np.lexsort((d, t))
-        t, d = t[order], d[order]
-        cnt = np.bincount(t, minlength=n_terms + 1)  # indexed by 1-based term id
-        offsets = np.zeros(n_terms + 2, dtype=np.int32)
-        offsets[1 : len(cnt) + 1] = np.cumsum(cnt)
-        offsets[len(cnt) + 1 :] = len(d)
-        minimal = np.full(n_terms + 2, INF_DOCID, dtype=np.int32)
-        starts = offsets[:-1]
-        ends = offsets[1:]
-        nonempty = ends > starts
-        minimal[:-1][nonempty] = d[starts[nonempty]]
+        with build_stage("build.postings"):
+            d, offsets, minimal = _csr(term_rows, docid_of_row, n_terms)
+            postings = jnp.asarray(d.astype(np.int32))
         packed = None
         if postings_codec is not None:
-            packed = pack_postings(d.astype(np.int32), postings_codec)
-            got = unpack_postings(packed)
-            assert (got == d).all(), "packed postings round-trip broke"
+            with build_stage("build.pack"):
+                packed = pack_postings(d.astype(np.int32), postings_codec)
+                got = unpack_postings(packed)
+                assert (got == d).all(), "packed postings round-trip broke"
         return InvertedIndex(
-            postings=jnp.asarray(d.astype(np.int32)),
+            postings=postings,
             offsets=jnp.asarray(offsets),
             minimal=jnp.asarray(minimal),
             n_terms=n_terms,
@@ -97,3 +80,30 @@ class InvertedIndex:
     def build_minimal_rmq(self) -> RangeMin:
         """RMQ over the minimal array for single-term queries (paper §3.3)."""
         return RangeMin.build(np.asarray(self.minimal))
+
+
+def _csr(term_rows, docid_of_row, n_terms):
+    """(term, docid) pairs -> (postings int64[P], offsets, minimal)."""
+    term_rows = np.asarray(term_rows, dtype=np.int64)
+    n, m = term_rows.shape
+    docs = np.broadcast_to(np.asarray(docid_of_row, dtype=np.int64)[:, None], (n, m))
+    mask = term_rows != 0
+    t = term_rows[mask]
+    d = docs[mask]
+    # dedup (term, doc) pairs — a term may repeat inside one completion
+    key = t * (np.int64(docid_of_row.max()) + 1) + d
+    uniq = np.unique(key)
+    t = (uniq // (np.int64(docid_of_row.max()) + 1)).astype(np.int64)
+    d = (uniq % (np.int64(docid_of_row.max()) + 1)).astype(np.int64)
+    order = np.lexsort((d, t))
+    t, d = t[order], d[order]
+    cnt = np.bincount(t, minlength=n_terms + 1)  # indexed by 1-based term id
+    offsets = np.zeros(n_terms + 2, dtype=np.int32)
+    offsets[1 : len(cnt) + 1] = np.cumsum(cnt)
+    offsets[len(cnt) + 1 :] = len(d)
+    minimal = np.full(n_terms + 2, INF_DOCID, dtype=np.int32)
+    starts = offsets[:-1]
+    ends = offsets[1:]
+    nonempty = ends > starts
+    minimal[:-1][nonempty] = d[starts[nonempty]]
+    return d, offsets, minimal

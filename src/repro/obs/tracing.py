@@ -68,10 +68,23 @@ is active each stage (and each ``runtime.lookup``) also enters a
 host plane on the device ops' clock; otherwise no annotation is made.
 ``install_gc_clock`` adds the collector's pauses, ``(generation, t0_ns,
 t1_ns)``, process-wide (``gc_pauses()``).
+
+The host index build records its stages on the same clock, process-wide,
+as ``(name, t0_ns, t1_ns, attrs)`` (``build_stage``, ``build_stages()``).
+Inside ``build_clock`` (``build_qac_index`` opens one) they tile the build:
+
+  build.corpus      ``build_corpus``: tokenising, vocabulary, dedup, the
+                    dictionary; attrs ``path`` is ``bulk`` (the whole-array
+                    pass) or ``loop`` (the per-query loop).
+  build.completions ``Completions.build``.
+  build.postings    docids and the inverted index's CSR arrays.
+  build.pack        the packed postings and their round-trip check.
+  build.rmq         the two range-minimum structures.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import contextvars
 import gc
 import json
@@ -323,3 +336,50 @@ def install_gc_clock():
 def gc_pauses() -> list[tuple]:
     """The recorded collections: ``(generation, t0_ns, t1_ns)``."""
     return list(_GC_PAUSES)
+
+
+_BUILD_STAGES: collections.deque = collections.deque(maxlen=1 << 12)
+_BUILD: contextvars.ContextVar = contextvars.ContextVar("build", default=None)
+
+
+class build_stage:
+    """``with build_stage(name, **attrs):`` one stage of the host index
+    build, recorded process-wide as ``(name, t0_ns, t1_ns, attrs)`` (the
+    newest 4096 are kept). ``attrs`` may be filled inside the block. Inside
+    ``build_clock`` the record runs from where the build's previous stage
+    ended, so the stages tile the build; outside it, the block alone."""
+
+    __slots__ = ("name", "attrs", "t0")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self):
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        cursor = _BUILD.get()
+        t0 = self.t0 if cursor is None else cursor[0]
+        _BUILD_STAGES.append((self.name, t0, t1, self.attrs))
+        if cursor is not None:
+            cursor[0] = t1
+        return False
+
+
+@contextlib.contextmanager
+def build_clock():
+    """One index build: the ``build_stage`` records made inside it tile it
+    from its entry on."""
+    token = _BUILD.set([time.perf_counter_ns()])
+    try:
+        yield
+    finally:
+        _BUILD.reset(token)
+
+
+def build_stages() -> list[tuple]:
+    """The recorded index build stages: ``(name, t0_ns, t1_ns, attrs)``."""
+    return list(_BUILD_STAGES)

@@ -128,6 +128,84 @@ def test_pack_roundtrip_unsorted_blocks():
         assert np.array_equal(unpack_postings(pk), v.astype(np.int32))
 
 
+def _ref_pack(v, codec):
+    """Per-block reference coder: one ``BitWriter`` pass over the blocks
+    in turn -> (words, base, meta, wordoff) as the format describes."""
+    v = np.asarray(v, dtype=np.int64)
+    nb = max(1, -(-len(v) // PACK_BLOCK))
+    vp = np.full(nb * PACK_BLOCK, v[-1] if len(v) else 0, dtype=np.int64)
+    vp[:len(v)] = v
+    bw, base, meta, wordoff = BitWriter(), [], [], []
+    for blk in vp.reshape(nb, PACK_BLOCK):
+        b0 = int(blk.min())
+        d = blk - b0
+        width = int(d.max()).bit_length()
+        low = max(width - 7, 0)
+        ef = (codec == "ef" and bool((np.diff(blk) >= 0).all())
+              and 8 + 4 * low < 4 * width)
+        base.append(b0)
+        wordoff.append(bw.n_bits() // 32)
+        if ef:
+            start = bw.n_bits()
+            bw.unary_many(np.diff(d >> low, prepend=np.int64(0)))
+            bw.pad_to(start + 256)
+            bw.write_many(d & ((1 << low) - 1), low)
+            meta.append(low | 64)
+        else:
+            bw.write_many(d, width)
+            meta.append(width)
+    n32 = bw.n_bits() // 32
+    w64 = bw.array()
+    words = np.zeros(max(n32, 1), np.uint32)
+    both = np.stack([w64 & 0xFFFFFFFF, w64 >> np.uint64(32)], 1).ravel()
+    words[:n32] = both[:n32]
+    return words, np.array(base), np.array(meta), np.array(wordoff)
+
+
+def _ref_unpack(words, base, meta, wordoff, n):
+    """Per-block reference decoder (``BitReader``) of ``_ref_pack``."""
+    out = []
+    for b0, m, off in zip(base, meta, wordoff):
+        w, ef = int(m) & 63, int(m) >> 6
+        seg = words[off:off + (8 if ef else 0) + 4 * w].astype(np.uint64)
+        if len(seg) % 2:
+            seg = np.append(seg, np.uint64(0))
+        r = BitReader(seg[0::2] | (seg[1::2] << np.uint64(32)))
+        if ef:
+            high = np.cumsum(r.unary_many(PACK_BLOCK))
+            r.pos = 256
+            out.append(b0 + ((high << w) | r.read_many(PACK_BLOCK, w)))
+        else:
+            out.append(b0 + r.read_many(PACK_BLOCK, w))
+    return np.concatenate(out)[:n]
+
+
+def _block_values(kind, n):
+    rng = np.random.default_rng(n)
+    if kind == "sorted":
+        return _sorted_values(rng, n, 1 << 24)
+    if kind == "unsorted":
+        return rng.integers(0, 2**31 - 1, size=n)
+    return np.full(n, 123456, dtype=np.int64)    # width-0 blocks
+
+
+@pytest.mark.parametrize("kind", ["sorted", "unsorted", "equal"])
+@pytest.mark.parametrize("n", [0, 1, 127, 128, 129, 10_000])
+@pytest.mark.parametrize("codec", ["ef", "bitpack"])
+def test_pack_matches_the_per_block_coder(codec, n, kind):
+    v = _block_values(kind, n)
+    pk = pack_postings(v, codec)
+    want = _ref_pack(v, codec)
+    for name, ref in zip(("words", "base", "meta", "wordoff"), want):
+        got = np.asarray(getattr(pk, name))
+        assert got.dtype == np.int32, name
+        np.testing.assert_array_equal(got.view(ref.dtype) if name == "words"
+                                      else got, ref, err_msg=name)
+    np.testing.assert_array_equal(unpack_postings(pk), v.astype(np.int32))
+    np.testing.assert_array_equal(
+        _ref_unpack(*want, n).astype(np.int32), v.astype(np.int32))
+
+
 def test_pack_rejects_unknown_codec():
     with pytest.raises(ValueError):
         pack_postings(np.arange(10), "snappy")

@@ -265,3 +265,78 @@ def test_hyb_baseline_matches_fwd(built):
         assert got == want, partial
         checked += 1
     assert checked >= 20
+
+
+# ---------------------------------------------------------------- corpus pass
+_CORPUS_CASES = {
+    "generator": (*_mini_corpus(seed=3, n=600, vocab=150), "bulk"),
+    "duplicates": (["b a", "b a", "a", "a", "c d e", "c  d e", "a", "b a"],
+                   [1.0, 3.0, 2.0, 2.0, 5.0, 7.0, 2.0, 3.0], "bulk"),
+    "whitespace": (["\ta \x0b\x0cb\r\n", " ", "", "\x1c\x1d\x1e\x1fc  d ",
+                    "a b", "  a\tb  ", "\n", "c d"],
+                   [1.0, 2.0, 3.0, 4.0, 0.5, 9.0, 1.0, 2.0], "bulk"),
+    "max_terms": (["a b c d e f g h", "a b c d e f g h i", "x",
+                   " ".join("abcdefghi")], [1.0, 2.0, 3.0, 4.0], "bulk"),
+    "long_terms": (["abcdefghijklmnopqrstuvwxyz0123 q",
+                    "abcdefghijklmnopqrstuvwxyz0124",
+                    "abcdefghijklmnopqrstuvwx", "q abcdefghijklmnopqrstuvwxyz"],
+                   [1.0, 2.0, 3.0, 4.0], "bulk"),
+    "short_terms": (["a", "a b", "ab", "a!", "!", "a !"],
+                    [1.0, 2.0, 3.0, 4.0, 5.0, 6.0], "bulk"),
+    "non_ascii": (["café au lait", "cafe au lait", "b"], [1.0, 2.0, 3.0],
+                  "loop"),
+    "nul": (["a\x00b c", "a b"], [1.0, 2.0], "loop"),
+    "control": (["a\x07b c", "a b"], [1.0, 2.0], "loop"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CORPUS_CASES))
+def test_corpus_pass_matches_the_loop(case):
+    """``build_corpus`` (the whole-array pass where the input allows it)
+    against the per-query loop, and the index built from each."""
+    from repro.core.builder import _corpus_loop, index_from_corpus
+    from repro.obs.tracing import build_stages
+    qs, sc, path = _CORPUS_CASES[case]
+    got = build_corpus(qs, sc)
+    assert build_stages()[-1][0] == "build.corpus"
+    assert build_stages()[-1][3] == {"path": path}
+    want = _corpus_loop(qs, sc, 8, 24)
+    (gd, g_rows, g_sc, g_kept), (wd, w_rows, w_sc, w_kept) = got, want
+    assert gd.n_terms == wd.n_terms and gd.max_chars == wd.max_chars
+    np.testing.assert_array_equal(np.asarray(gd.chars), np.asarray(wd.chars))
+    np.testing.assert_array_equal(np.asarray(gd.keys), np.asarray(wd.keys))
+    assert g_rows.dtype == w_rows.dtype and g_sc.dtype == w_sc.dtype
+    np.testing.assert_array_equal(g_rows, w_rows)
+    np.testing.assert_array_equal(g_sc.view(np.int64), w_sc.view(np.int64))
+    assert g_kept == w_kept and all(type(k) is str for k in g_kept)
+    qidx, kept, _ = build_qac_index(qs, sc)
+    ref = index_from_corpus(wd, w_rows, w_sc)
+    assert kept == w_kept
+    g_leaves, g_tree = jax.tree_util.tree_flatten(qidx)
+    w_leaves, w_tree = jax.tree_util.tree_flatten(ref)
+    assert g_tree == w_tree
+    for a, b in zip(g_leaves, w_leaves):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_corpus_pass_keeps_the_first_of_equal_best_scores():
+    """Signed zeros compare equal: the first query of the best score wins,
+    as in the loop."""
+    _, _, sc, kept = build_corpus(["a", "a", "b", "b"], [0.0, -0.0, -0.0, 0.0])
+    assert kept == ["a", "b"]
+    assert np.signbit(sc).tolist() == [False, True]
+
+
+def test_build_stages_tile_the_build():
+    from repro.obs.tracing import build_stages
+    qs, sc = _mini_corpus(seed=4, n=300, vocab=90)
+    build_qac_index(qs, sc)
+    recs = build_stages()[-5:]
+    assert [r[0] for r in recs] == ["build.corpus", "build.completions",
+                                    "build.postings", "build.pack",
+                                    "build.rmq"]
+    assert recs[0][3] == {"path": "bulk"}
+    for (_, _, end, _), (_, start, _, _) in zip(recs, recs[1:]):
+        assert start == end
+    assert all(t1 >= t0 for _, t0, t1, _ in recs)
